@@ -254,6 +254,7 @@ def elastic_fleet(
     resize_batch: bool = True,
     shared_cache: bool = True,
     launch_share: bool = True,
+    engine: str = "jax",
 ) -> Cluster:
     """jax-engine fleet over the anchor+grow mix — the engine whose
     per-job resize loop pays one kernel launch per candidate, i.e. the
@@ -264,13 +265,14 @@ def elastic_fleet(
     a handful of jobs).  ``shared_cache=False`` reverts to private
     per-node caches and ``launch_share=False`` disables the tie-frontier
     launch memo — together the pre-PR configuration the solo leg
-    measures."""
+    measures.  ``engine="vector"`` runs the same fleet on the numpy
+    engine, the reference the chip smoke compares against."""
     cache = DecisionCache() if shared_cache else True
 
     def policy_for(spec, truth):
         return EcoSched(
             ProfiledPerfModel(truth, noise=0.0, seed=1),
-            lam=LAM, tau=TAU, window=8, engine="jax", cache=cache,
+            lam=LAM, tau=TAU, window=8, engine=engine, cache=cache,
             resize_batch=resize_batch, launch_share=launch_share,
         )
 
@@ -290,14 +292,14 @@ def elastic_fleet(
     )
 
 
-def _elastic_schedule_of(res) -> List[Tuple]:
+def elastic_schedule_of(res) -> List[Tuple]:
     return [
         (r.job, r.node, r.g, r.f, r.start, r.end, r.kind, r.segment)
         for r in res.records
     ]
 
 
-def _run_elastic(
+def run_elastic(
     n_nodes: int,
     rate: float,
     n_jobs: int,
@@ -306,8 +308,9 @@ def _run_elastic(
     staged: bool,
     shared_cache: bool,
     launch_share: bool = True,
+    engine: str = "jax",
 ):
-    """One elastic leg; returns (result, elapsed_s, resize_stage_served)."""
+    """One elastic leg; returns (result, elapsed_s, run)."""
     from repro.core.events import EVT_ARRIVAL
 
     arrivals = sorted(_stream(rate, n_jobs), key=lambda a: a.t)
@@ -317,6 +320,7 @@ def _run_elastic(
         resize_batch=resize_batch,
         shared_cache=shared_cache,
         launch_share=launch_share,
+        engine=engine,
     )
     run = cl.open_run(
         apps=[f"app{i}" for i in range(N_APPS)],
@@ -334,11 +338,12 @@ def _run_elastic(
     run.loop.run()
     res = run.finalize()
     elapsed = time.perf_counter() - t0
-    served = sum(
-        getattr(s.policy, "resize_stage_served", 0)
-        for s in run.sims.values()
-    )
-    return res, elapsed, served
+    return res, elapsed, run
+
+
+def policy_sum(run, attr: str) -> int:
+    """One EcoSched counter summed over a run's per-node policies."""
+    return sum(getattr(s.policy, attr, 0) for s in run.sims.values())
 
 
 def elastic_case(
@@ -363,10 +368,11 @@ def elastic_case(
     best = {name: (float("inf"), None, 0) for name in legs}
     for _ in range(repeats):
         for name, kw in legs.items():
-            res, elapsed, served = _run_elastic(n_nodes, rate, n_jobs, **kw)
+            res, elapsed, run = run_elastic(n_nodes, rate, n_jobs, **kw)
             if elapsed < best[name][0]:
+                served = policy_sum(run, "resize_stage_served")
                 best[name] = (elapsed, res, served)
-    assert _elastic_schedule_of(best["batched"][1]) == _elastic_schedule_of(
+    assert elastic_schedule_of(best["batched"][1]) == elastic_schedule_of(
         best["solo"][1]
     ), f"batched COMPLETE path diverged from per-job loop at {n_nodes} nodes"
     assert best["batched"][1].total_energy == best["solo"][1].total_energy

@@ -16,6 +16,15 @@ def main() -> None:
     ap.add_argument("--quiet", action="store_true")
     args, _ = ap.parse_known_args()
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    # Every jax-engine bench below runs in this process, which holds the
+    # chip from its first kernel launch.  bench_service then spawns
+    # ``python -m repro.cli daemon`` children: that is safe only while the
+    # daemon stays on the numpy engine (cli.py builds its EcoSched with the
+    # default ``vector`` engine and never imports JAX), since a second
+    # process cannot open a chip this one holds.
     from benchmarks import (
         bench_cluster,
         bench_cluster_throughput,

@@ -140,6 +140,9 @@ class EcoSched:
         # consumed stagings (observability + test hook).
         self._staged: Optional[dict] = None
         self.stage_served = 0
+        # decisions the vector/jax engines handed to the python reference
+        # because the window overflowed the int64 action-set keys
+        self.python_fallbacks = 0
         # batched elastic resize scoring (ISSUE 10 tentpole): collect every
         # eligible running job's candidate window and score them through
         # one multi-window kernel launch instead of one launch per job.
@@ -326,6 +329,7 @@ class EcoSched:
         except OverflowError:
             # windows too wide for the engine's int64 action-set keys
             # (never the pod-scale target); the reference path has no limit
+            self.python_fallbacks += 1
             return self._best_python(specs, view)
         used_nonempty = False
         i = batch.best_cached(self.lookahead)
@@ -455,6 +459,7 @@ class EcoSched:
         try:
             batch = self._enumerate(specs, view)
         except OverflowError:
+            self.python_fallbacks += 1
             return self._best_python(specs, view)
         from repro.kernels.score_reduce import score_reduce
 

@@ -15,19 +15,16 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-from repro import compat
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None) -> Mesh:
     """jax.make_mesh wrapper pinning Auto axis types (pjit-style propagation)."""
+    auto = (AxisType.Auto,) * len(axes)
     if devices is None:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes), **compat.auto_axis_types(len(axes))
-        )
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
     arr = np.asarray(devices).reshape(tuple(shape))
-    return Mesh(arr, tuple(axes), **compat.auto_axis_types(len(axes)))
+    return Mesh(arr, tuple(axes), axis_types=auto)
 
 
 def carve_submesh(

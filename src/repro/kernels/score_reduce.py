@@ -14,21 +14,24 @@ space exceeds 10^5 rows per event — and the joint (count × frequency) mode
 set is 4–8× larger still; this module reduces it in one fused kernel
 instead of a chain of numpy temporaries.
 
-Backend selection mirrors ``kernels/ops.py``: on TPU the Pallas kernel
-runs compiled (Mosaic); everywhere else ``REPRO_KERNELS`` picks
-``interpret`` (kernel body op-by-op on CPU — the validation target) or
-``ref`` (pure jnp, fast enough for CI; the default off-TPU).  The Pallas
-grid tiles rows into blocks; each grid step writes its block's scores and
-a per-block (min score, best count, best row) triple, and a tiny jnp
-combine selects the global winner across blocks — so the reduction never
-materializes on the host.
+``backend_mode`` picks how it runs: on TPU the Pallas kernel runs
+compiled (Mosaic, mode ``pallas``); off-TPU ``REPRO_KERNELS`` picks
+``interpret`` (the kernel body op-by-op on CPU — the validation target)
+or ``ref`` (the same elementwise ops in plain jnp, fast enough for CI;
+the default off-TPU).  An unknown ``REPRO_KERNELS`` value raises.
 
-λ, G_free, M and λ_f ride in an SMEM params row (traced, not static):
-sweeping node fill levels or frequency-conservatism weights does not
-recompile.  Rows are padded to a power of two
-and slots to a multiple of 8, so the jit cache stays small.  Scores are
-float32 — parity vs the float64 numpy engine is ≤1e-6 over seeded random
-windows (tests/test_score_reduce.py).
+One kernel serves all three entry points.  Its grid tiles a row-packed
+table into blocks and writes each row's score and total count; the
+Eq. (1) scalars λ, G_free, M and λ_f ride as per-row (traced) columns,
+so sweeping node fill levels or frequency-conservatism weights does not
+recompile.  Each entry point then takes its tie-broken argmin in jnp on
+the device — over one window (``score_reduce``), per node of a stacked
+batch (``score_reduce_batch``) or per packed window
+(``score_reduce_multi``) — so the reduction never materializes on the
+host.  Rows are padded to a power of two and slots to a multiple of 8,
+so the jit cache stays small.  Scores are float32 — parity vs the
+float64 numpy engine is ≤1e-6 over seeded random windows
+(tests/test_score_reduce.py).
 """
 from __future__ import annotations
 
@@ -41,24 +44,29 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-import jax.experimental.pallas.tpu as pltpu
 
 _BLOCK_B = 256  # candidate rows per grid step
 _SLOT_PAD = 8  # slot (action-size) axis padded to a multiple of this
+_MODES = ("pallas", "interpret", "ref")
 
 
-def _backend_mode() -> str:
-    forced = os.environ.get("REPRO_KERNELS", "")
-    if forced:
-        return forced  # "pallas" | "interpret" | "ref"
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+def backend_mode(mode: Optional[str] = None) -> str:
+    """``mode`` when given, else the one ``REPRO_KERNELS`` forces, else
+    ``pallas`` on TPU and ``ref`` elsewhere.  An unknown value raises
+    instead of silently running the compiled kernel."""
+    mode = mode or os.environ.get("REPRO_KERNELS", "") or (
+        "pallas" if jax.default_backend() == "tpu" else "ref"
+    )
+    if mode not in _MODES:
+        raise ValueError(f"kernel mode {mode!r} is not one of {_MODES}")
+    return mode
 
 
 def _row_scores(dev, g, f, n, bias, mask, lam, g_free, M, lam_f):
-    """(B, 1) masked Eq. (1) scores from (B, S)/(B, 1) blocks.  The
-    frequency term is λ_f·mean(f); at λ_f = 0 (or an all-zero f plane —
-    single-frequency windows) it contributes exactly +0.0, keeping scores
-    bit-identical to the count-only kernel."""
+    """(B, 1) masked Eq. (1) scores and total counts from (B, S)/(B, 1)
+    blocks.  The frequency term is λ_f·mean(f); at λ_f = 0 (or an all-zero
+    f plane — single-frequency windows) it contributes exactly +0.0,
+    keeping scores bit-identical to the count-only reduction."""
     tot = jnp.sum(g, axis=1, keepdims=True)
     n_eff = jnp.maximum(n, 1.0)
     s = (
@@ -70,89 +78,68 @@ def _row_scores(dev, g, f, n, bias, mask, lam, g_free, M, lam_f):
     return jnp.where(mask > 0, s, jnp.inf), tot
 
 
-def _pick(scores, tot, idx, idx_cap):
-    """Tie-broken argmin: min score, then max total count, then min index.
-    Returns (min score, winning count, winning index)."""
+def _kernel(dev_ref, g_ref, f_ref, n_ref, bias_ref, mask_ref,
+            lam_ref, gfree_ref, m_ref, lamf_ref, scores_ref, tot_ref):
+    """Grid step i: row-block i of a row-packed table.  Eq. (1) params are
+    per-row columns, so one kernel serves a single window, a stack of
+    nodes and many packed windows alike; every argmin is jnp outside."""
+    scores, tot = _row_scores(
+        dev_ref[:], g_ref[:], f_ref[:], n_ref[:], bias_ref[:], mask_ref[:],
+        lam_ref[:], gfree_ref[:], m_ref[:], lamf_ref[:],
+    )
+    scores_ref[:] = scores
+    tot_ref[:] = tot
+
+
+def _score_rows(dev, g, f, n, bias, mask, lam, gfree, m, lamf, mode: str):
+    """(B, 1) scores and total counts of a (B, S) table whose params are
+    (B, 1) columns: the Pallas kernel (compiled or interpreted) or the
+    same elementwise ops in plain jnp."""
+    if mode == "ref":
+        return _row_scores(dev, g, f, n, bias, mask, lam, gfree, m, lamf)
+    b_pad, s_pad = dev.shape
+    col = pl.BlockSpec((_BLOCK_B, 1), lambda i: (i, 0))
+    plane = pl.BlockSpec((_BLOCK_B, s_pad), lambda i: (i, 0))
+    return pl.pallas_call(
+        _kernel,
+        grid=(b_pad // _BLOCK_B,),
+        in_specs=[plane, plane, plane, col, col, col, col, col, col, col],
+        out_specs=[col, col],
+        out_shape=[
+            jax.ShapeDtypeStruct((b_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b_pad, 1), jnp.float32),
+        ],
+        interpret=(mode == "interpret"),
+    )(dev, g, f, n, bias, mask, lam, gfree, m, lamf)
+
+
+def _param_cols(params, rows: int):
+    """(D, 4) [λ, G_free, M, λ_f] rows -> four (D·rows, 1) columns, each
+    node's scalars repeated over its ``rows`` candidate rows."""
+    return [jnp.repeat(params[:, k], rows)[:, None] for k in range(4)]
+
+
+def _argmin(scores, tot):
+    """Tie-broken argmin of (B, 1) scores: min score, then max total
+    count, then min row.  Returns ((B,) scores, winning row or -1 when no
+    row is feasible)."""
+    b = scores.shape[0]
+    idx = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
     m = jnp.min(scores)
     tie = scores == m
     t_best = jnp.max(jnp.where(tie, tot, -1.0))
     cand = tie & (tot == t_best)
-    i = jnp.min(jnp.where(cand, idx, idx_cap))
-    return m, t_best, i
-
-
-def _kernel(params_ref, dev_ref, g_ref, f_ref, n_ref, bias_ref, mask_ref,
-            scores_ref, bmin_ref, btot_ref, bidx_ref):
-    lam = params_ref[0, 0]
-    g_free = params_ref[0, 1]
-    M = params_ref[0, 2]
-    lam_f = params_ref[0, 3]
-    scores, tot = _row_scores(
-        dev_ref[:], g_ref[:], f_ref[:], n_ref[:], bias_ref[:], mask_ref[:],
-        lam, g_free, M, lam_f,
-    )
-    scores_ref[:] = scores
-    bb = scores.shape[0]
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (bb, 1), 0)
-    m, t_best, r = _pick(scores, tot, ridx, jnp.int32(bb))
-    bmin_ref[0, 0] = m
-    btot_ref[0, 0] = t_best
-    bidx_ref[0, 0] = pl.program_id(0) * bb + r
-
-
-def _combine(scores, bmin, btot, bidx, b_pad):
-    """Global winner across per-block (min, count, index) triples."""
-    mg = jnp.min(bmin)
-    tie = bmin == mg
-    t_best = jnp.max(jnp.where(tie, btot, -1.0))
-    cand = tie & (btot == t_best)
-    idx = jnp.min(jnp.where(cand, bidx, jnp.int32(b_pad)))
-    best = jnp.where(jnp.isinf(mg), jnp.int32(-1), idx)
-    return scores[:, 0], best
-
-
-def _node_reduce(params, dev, g, f, n, bias, mask):
-    """Single-node Eq. (1) reduction in pure jnp.  ``params`` is one (4,)
-    [λ, G_free, M, λ_f] row; vmapping this over a leading node axis is the
-    batched ref path, so per-node results are the same elementwise ops as
-    the solo ref path."""
-    b_pad = dev.shape[0]
-    scores, tot = _row_scores(
-        dev, g, f, n, bias, mask, params[0], params[1], params[2], params[3]
-    )
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (b_pad, 1), 0)
-    m, t_best, i = _pick(scores, tot, ridx, jnp.int32(b_pad))
-    best = jnp.where(jnp.isinf(m), jnp.int32(-1), i)
-    return scores[:, 0], best
+    i = jnp.min(jnp.where(cand, idx, jnp.int32(b)))
+    return scores[:, 0], jnp.where(jnp.isinf(m), jnp.int32(-1), i)
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
 def _reduce_jit(params, dev, g, f, n, bias, mask, *, mode: str):
-    b_pad, s_pad = dev.shape
-    if mode == "ref":
-        return _node_reduce(params[0], dev, g, f, n, bias, mask)
-    nb = b_pad // _BLOCK_B
-    col = pl.BlockSpec((_BLOCK_B, 1), lambda i: (i, 0))
-    blk = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    plane = pl.BlockSpec((_BLOCK_B, s_pad), lambda i: (i, 0))
-    scores, bmin, btot, bidx = pl.pallas_call(
-        _kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            plane, plane, plane,
-            col, col, col,
-        ],
-        out_specs=[col, blk, blk, blk],
-        out_shape=[
-            jax.ShapeDtypeStruct((b_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.int32),
-        ],
-        interpret=(mode == "interpret"),
-    )(params, dev, g, f, n, bias, mask)
-    return _combine(scores, bmin, btot, bidx, b_pad)
+    scores, tot = _score_rows(
+        dev, g, f, n, bias, mask, *_param_cols(params, dev.shape[0]),
+        mode=mode,
+    )
+    return _argmin(scores, tot)
 
 
 def _pad_rows(a: np.ndarray, b_pad: int) -> np.ndarray:
@@ -210,7 +197,7 @@ def score_reduce(
     params = np.array([[lam, g_free, M, lam_f]], dtype=np.float32)
     scores, best = _reduce_jit(
         params, dev_p, g_p, f_p, n_p, bias_p, mask_p,
-        mode=mode or _backend_mode(),
+        mode=backend_mode(mode),
     )
     return np.asarray(scores)[:B], int(best)
 
@@ -221,57 +208,18 @@ def score_reduce(
 # ---------------------------------------------------------------------------
 
 
-def _kernel_batch(params_ref, dev_ref, g_ref, f_ref, n_ref, bias_ref,
-                  mask_ref, scores_ref, bmin_ref, btot_ref, bidx_ref):
-    """Grid step (d, i): row-block i of node d.  Each node's [λ, G_free,
-    M, λ_f] row rides in SMEM, selected by the node grid axis — per-node
-    free-unit/alive-unit scalars without recompiles or plane broadcasts."""
-    lam = params_ref[0, 0]
-    g_free = params_ref[0, 1]
-    M = params_ref[0, 2]
-    lam_f = params_ref[0, 3]
-    scores, tot = _row_scores(
-        dev_ref[0], g_ref[0], f_ref[0], n_ref[0], bias_ref[0], mask_ref[0],
-        lam, g_free, M, lam_f,
-    )
-    scores_ref[0] = scores
-    bb = scores.shape[0]
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (bb, 1), 0)
-    m, t_best, r = _pick(scores, tot, ridx, jnp.int32(bb))
-    bmin_ref[0, 0, 0] = m
-    btot_ref[0, 0, 0] = t_best
-    bidx_ref[0, 0, 0] = pl.program_id(1) * bb + r
-
-
 @functools.partial(jax.jit, static_argnames=("mode",))
 def _reduce_batch_jit(params, dev, g, f, n, bias, mask, *, mode: str):
     d_pad, b_pad, s_pad = dev.shape
-    if mode == "ref":
-        return jax.vmap(_node_reduce)(params, dev, g, f, n, bias, mask)
-    nb = b_pad // _BLOCK_B
-    col = pl.BlockSpec((1, _BLOCK_B, 1), lambda d, i: (d, i, 0))
-    blk = pl.BlockSpec((1, 1, 1), lambda d, i: (d, i, 0))
-    plane = pl.BlockSpec((1, _BLOCK_B, s_pad), lambda d, i: (d, i, 0))
-    scores, bmin, btot, bidx = pl.pallas_call(
-        _kernel_batch,
-        grid=(d_pad, nb),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda d, i: (d, 0),
-                         memory_space=pltpu.SMEM),
-            plane, plane, plane,
-            col, col, col,
-        ],
-        out_specs=[col, blk, blk, blk],
-        out_shape=[
-            jax.ShapeDtypeStruct((d_pad, b_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((d_pad, nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((d_pad, nb, 1), jnp.float32),
-            jax.ShapeDtypeStruct((d_pad, nb, 1), jnp.int32),
-        ],
-        interpret=(mode == "interpret"),
-    )(params, dev, g, f, n, bias, mask)
-    combine = jax.vmap(lambda s, m, t, i: _combine(s, m, t, i, b_pad))
-    return combine(scores, bmin, btot, bidx)
+    rows = d_pad * b_pad
+    scores, tot = _score_rows(
+        dev.reshape(rows, s_pad), g.reshape(rows, s_pad),
+        f.reshape(rows, s_pad), n.reshape(rows, 1), bias.reshape(rows, 1),
+        mask.reshape(rows, 1), *_param_cols(params, b_pad), mode=mode,
+    )
+    return jax.vmap(_argmin)(
+        scores.reshape(d_pad, b_pad, 1), tot.reshape(d_pad, b_pad, 1)
+    )
 
 
 def score_reduce_batch(
@@ -327,7 +275,8 @@ def score_reduce_batch(
             mask[k, :B, 0] = np.asarray(rm, dtype=np.float32).reshape(B)
         params[k] = [r["lam"], r["g_free"], r["M"], r.get("lam_f", 0.0)]
     scores, best = _reduce_batch_jit(
-        params, dev, g, f, n, bias, mask, mode=mode or _backend_mode()
+        params, dev, g, f, n, bias, mask,
+        mode=backend_mode(mode),
     )
     scores = np.asarray(scores)
     best = np.asarray(best)
@@ -345,47 +294,17 @@ def score_reduce_batch(
 # ---------------------------------------------------------------------------
 
 
-def _kernel_multi(dev_ref, g_ref, f_ref, n_ref, bias_ref, mask_ref,
-                  lam_ref, gfree_ref, m_ref, lamf_ref,
-                  scores_ref, tot_ref):
-    """Grid step i: row-block i of the packed multi-window table.  Eq. (1)
-    params are per-row columns (windows straddle block boundaries freely);
-    the per-window argmin is a segmented combine outside the kernel."""
-    scores, tot = _row_scores(
-        dev_ref[:], g_ref[:], f_ref[:], n_ref[:], bias_ref[:], mask_ref[:],
-        lam_ref[:], gfree_ref[:], m_ref[:], lamf_ref[:],
-    )
-    scores_ref[:] = scores
-    tot_ref[:] = tot
-
-
 @functools.partial(jax.jit, static_argnames=("n_windows", "mode"))
 def _reduce_multi_jit(lam, gfree, m, lamf, dev, g, f, n, bias, mask,
                       wid, starts, *, n_windows: int, mode: str):
-    b_pad, s_pad = dev.shape
-    if mode == "ref":
-        scores2, tot2 = _row_scores(
-            dev, g, f, n, bias, mask, lam, gfree, m, lamf
-        )
-    else:
-        nb = b_pad // _BLOCK_B
-        col = pl.BlockSpec((_BLOCK_B, 1), lambda i: (i, 0))
-        plane = pl.BlockSpec((_BLOCK_B, s_pad), lambda i: (i, 0))
-        scores2, tot2 = pl.pallas_call(
-            _kernel_multi,
-            grid=(nb,),
-            in_specs=[plane, plane, plane, col, col, col, col, col, col, col],
-            out_specs=[col, col],
-            out_shape=[
-                jax.ShapeDtypeStruct((b_pad, 1), jnp.float32),
-                jax.ShapeDtypeStruct((b_pad, 1), jnp.float32),
-            ],
-            interpret=(mode == "interpret"),
-        )(dev, g, f, n, bias, mask, lam, gfree, m, lamf)
+    b_pad = dev.shape[0]
+    scores2, tot2 = _score_rows(
+        dev, g, f, n, bias, mask, lam, gfree, m, lamf, mode=mode
+    )
     scores = scores2[:, 0]
     tot = tot2[:, 0]
     # segmented tie-broken argmin — the same (min score, max count, min
-    # row) combine as _pick, scatter-reduced per window id.  Pad rows
+    # row) combine as _argmin, scatter-reduced per window id.  Pad rows
     # belong to a dummy window (their masked inf scores never matter).
     seg_min = jnp.full((n_windows,), jnp.inf, dtype=scores.dtype)
     m_w = seg_min.at[wid].min(scores)
@@ -472,7 +391,7 @@ def score_reduce_multi(
         off += B
     scores, best = _reduce_multi_jit(
         lam, gfree, m, lamf, dev, g, f, n, bias, mask, wid, starts,
-        n_windows=n_windows, mode=mode or _backend_mode(),
+        n_windows=n_windows, mode=backend_mode(mode),
     )
     scores = np.asarray(scores)
     best = np.asarray(best)

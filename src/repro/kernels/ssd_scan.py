@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro import compat
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *, chunk: int):
     ci = pl.program_id(2)
@@ -118,7 +116,7 @@ def ssd_scan(
             jax.ShapeDtypeStruct((B, nh, hp, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hp, N), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

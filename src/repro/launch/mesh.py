@@ -4,8 +4,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips) mesh.
@@ -16,4 +14,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **compat.auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -167,7 +167,7 @@ def moe_apply_ep(
     """Expert-parallel MoE over ``mesh`` (model axis = EP)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok

@@ -77,8 +77,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 
 def cost_summary(cost) -> Dict[str, float]:
-    if isinstance(cost, (list, tuple)):  # jax 0.4: one dict per computation
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     if byts == 0.0:

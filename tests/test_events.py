@@ -284,13 +284,11 @@ def test_frac_at_tracks_useful_work():
     assert rj.frac_at(9999.0) == 1.0
 
 
-def test_resize_identical_across_scoring_backends():
+def test_resize_identical_across_scoring_backends(monkeypatch):
     """The switch-cost-biased resize scoring runs through whichever backend
     the policy uses — vector argmin, pure-Python reference, or the Pallas
     score-reduce kernel (interpret fallback on CPU) — with one decision."""
-    import os
-
-    os.environ.setdefault("REPRO_KERNELS", "interpret")
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
     node = Node(4, 2, 10.0)
     cfg = ElasticConfig(resize=True, ckpt_time=30.0, restart_time=15.0,
                         min_gain_s=60.0)

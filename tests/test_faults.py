@@ -156,11 +156,11 @@ def test_seeded_job_crash_trace_is_deterministic():
     assert (r1.makespan, r1.total_energy) == (r2.makespan, r2.total_energy)
 
 
-def test_fault_trace_identical_across_engines():
+def test_fault_trace_identical_across_engines(monkeypatch):
     """The crash hazard is a pure function of (job, segment), never of
     the engine backend — seeded fault traces are bit-identical across
     the vector, pure-Python, and Pallas (interpret) scorers."""
-    os.environ.setdefault("REPRO_KERNELS", "interpret")
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
     node = Node(4, 2, 10.0)
     out = {}
     for eng in ("vector", "python", "jax"):

@@ -1,16 +1,15 @@
 """Sharding rules: TP-divisibility padding and spec validity for all archs."""
 import jax
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.configs import ARCHS, get_config
 from repro.distributed import sharding as shd
 from repro.models import Runtime, build_model
 
-# constructed via compat: the AbstractMesh signature changed across JAX 0.4/0.5
-MESH_1POD = abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("name", sorted(ARCHS))

@@ -52,12 +52,12 @@ tests/test_decision_cache.py).
 """
 from __future__ import annotations
 
-import time as _time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.arrivals import Arrival
 from repro.core.events import EVT_ARRIVAL, EVT_MIGRATE, ElasticConfig, EventLoop
 from repro.core.faults import FaultConfig, FaultInjector
@@ -1037,9 +1037,10 @@ class ClusterRun:
         self._frag_t = 0.0
         self._frag_cur = 0.0
         self._frag_peak = 0.0
-        # run-level decision-phase clocks (ISSUE 10): dispatch routing and
-        # cross-node kernel staging are cluster work, not node work — the
-        # per-node clocks (launch/resize/migrate) live on each NodeSim
+        # seconds in this run's sched.route and sched.stage spans of the
+        # program tracer (repro.obs): routing and cross-node staging are
+        # cluster work, not node work -- the per-node phases
+        # (launch/resize/migrate) live on each NodeSim
         self._dispatch_time = 0.0
         self._stage_time = 0.0
         if max_events is None:
@@ -1164,18 +1165,18 @@ class ClusterRun:
             self._frag_peak = cur
 
     def _prepare_batch(self, names: Sequence[str], t: float) -> None:
-        t0 = _time.perf_counter()
+        obs.start("sched.stage")
         try:
             self._stage_arrival_batch(names, t)
         finally:
-            self._stage_time += _time.perf_counter() - t0
+            self._stage_time += obs.stop() / 1e9
 
     def _prepare_complete_batch(self, pairs, t: float) -> None:
-        t0 = _time.perf_counter()
+        obs.start("sched.stage")
         try:
             self._stage_complete_batch(pairs, t)
         finally:
-            self._stage_time += _time.perf_counter() - t0
+            self._stage_time += obs.stop() / 1e9
 
     def _stage_arrival_batch(self, names: Sequence[str], t: float) -> None:
         """Fleet-batched decision staging (ISSUE 9): when a same-instant
@@ -1305,13 +1306,20 @@ class ClusterRun:
             i += len(rl)
 
     def route(self, arr: Arrival, t: float) -> Optional[str]:
+        """Route one arrival to a node and enqueue it there; the node's
+        name, or None when the arrival is dropped or held back."""
+        obs.start("sched.route")
+        try:
+            return self._route(arr, t)
+        finally:
+            self._dispatch_time += obs.stop() / 1e9
+
+    def _route(self, arr: Arrival, t: float) -> Optional[str]:
         if arr.name in self._cancelled:
             return None  # cancelled between submit and its ARRIVAL pop
         state = self.state
         ai = state.app_index[arr.app]
-        t0 = _time.perf_counter()
         ni = self.dispatcher.route_indexed(ai, self._dispatch_state, t)
-        self._dispatch_time += _time.perf_counter() - t0
         if ni < 0:
             if self.faults is not None and bool(self._fits_healthy[:, ai].any()):
                 # every node that can host this app is currently failed or

@@ -74,10 +74,10 @@ control-plane drivers need to resume.
 from __future__ import annotations
 
 import heapq
-import time as _time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core.faults import FaultConfig, FaultInjector
 
 # Event kinds.  ARRIVAL/COMPLETE keep the pre-refactor numeric order
@@ -104,6 +104,9 @@ EVENT_NAMES = {
     EVT_JOB_FAIL: "JOB_FAIL",
     EVT_RETRY: "RETRY",
 }
+
+# the program tracer's span for each head event's kind (repro.obs)
+_LOOP_SPAN = {k: f"loop.{v}" for k, v in EVENT_NAMES.items()}
 
 # the self-regenerating fault timeline: not "work", so an otherwise-idle
 # batch run can stop while the heap still carries the next failure cycle
@@ -354,7 +357,11 @@ class EventLoop:
             raise RuntimeError(self.cap_msg)
         t, kind, payload = q.pop()
         self.now = t
-        self._dispatch(t, kind, payload)
+        obs.start(_LOOP_SPAN.get(kind, "loop"))
+        try:
+            self._dispatch(t, kind, payload)
+        finally:
+            obs.stop()
         return True
 
     def run_until(self, t_max: float) -> None:
@@ -550,19 +557,13 @@ class EventLoop:
         cfg = self.elastic
         sim = self.sims[nm]
         if cfg.resize and cfg.resize_before_backfill:
-            t0 = _time.perf_counter()
-            self._try_resize(nm, t)
-            sim.resize_time += _time.perf_counter() - t0
+            sim.resize_time += _phase("sched.resize", self._try_resize, nm, t)
         if sim.waiting:
             self._schedule(nm)
         if cfg.resize and not cfg.resize_before_backfill:
-            t0 = _time.perf_counter()
-            self._try_resize(nm, t)
-            sim.resize_time += _time.perf_counter() - t0
+            sim.resize_time += _phase("sched.resize", self._try_resize, nm, t)
         if cfg.migrate and self.migrate_candidate is not None:
-            t0 = _time.perf_counter()
-            self._try_migrate(nm, t)
-            sim.migrate_time += _time.perf_counter() - t0
+            sim.migrate_time += _phase("sched.migrate", self._try_migrate, nm, t)
 
     def _try_resize(self, nm: str, t: float) -> None:
         sim = self.sims[nm]
@@ -601,3 +602,13 @@ class EventLoop:
         self.queue.push(
             t + self.elastic.migration_delay, EVT_MIGRATE, (nm, job, state)
         )
+
+
+def _phase(span: str, fn: Callable[[str, float], None], nm: str, t: float) -> float:
+    """``fn(nm, t)`` inside the tracer's span ``span``; its seconds."""
+    obs.start(span)
+    try:
+        fn(nm, t)
+    finally:
+        ns = obs.stop()
+    return ns / 1e9

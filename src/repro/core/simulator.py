@@ -26,10 +26,10 @@ and adds nothing to the static path.
 """
 from __future__ import annotations
 
-import time as _time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+from repro import obs
 from repro.core.events import EVT_ARRIVAL, ElasticConfig, EventLoop
 from repro.core.faults import FaultConfig, FaultInjector
 from repro.core.placement import PlacementState
@@ -121,10 +121,12 @@ class NodeSim:
         self.t = 0.0
         self.busy_energy = 0.0
         self.idle_unit_seconds = 0.0
+        # seconds in this node's spans of the program tracer (repro.obs):
+        # sched.decide, sched.resize and sched.migrate
         self.decision_time = 0.0
         self.decision_events = 0
-        self.resize_time = 0.0  # wall-clock inside the resize phase
-        self.migrate_time = 0.0  # wall-clock inside the migration phase
+        self.resize_time = 0.0
+        self.migrate_time = 0.0
         # elastic bookkeeping (inert unless the substrate drives it)
         self.progress: Dict[str, float] = {}  # job -> completed-work fraction
         self.needs_restart: Set[str] = set()  # next launch pays restart_time
@@ -183,11 +185,13 @@ class NodeSim:
     def invoke_policy(self) -> List[RunningJob]:
         """One scheduling event; returns the newly launched jobs (the owner
         pushes their completion events)."""
-        t0 = _time.perf_counter()
-        launches: List[Launch] = (
-            self.policy.on_event(self.node_view(), list(self.waiting)) or []
-        )
-        self.decision_time += _time.perf_counter() - t0
+        obs.start("sched.decide")
+        try:
+            launches: List[Launch] = (
+                self.policy.on_event(self.node_view(), list(self.waiting)) or []
+            )
+        finally:
+            self.decision_time += obs.stop() / 1e9
         self.decision_events += 1
         out: List[RunningJob] = []
         for ln in launches:

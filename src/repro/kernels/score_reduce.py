@@ -32,6 +32,12 @@ host.  Rows are padded to a power of two and slots to a multiple of 8,
 so the jit cache stays small.  Scores are float32 — parity vs the
 float64 numpy engine is ≤1e-6 over seeded random windows
 (tests/test_score_reduce.py).
+
+Each entry point runs in three spans of the program's tracer
+(``repro.obs``): ``kernel.pack`` (padding and packing on the host),
+``kernel.call`` (the jitted call) and ``kernel.fetch`` (the blocking reads
+of the answer), and counts its launch and the arrays it hands the device.
+The Pallas kernel is named ``eq1_row_scores`` in the device trace.
 """
 from __future__ import annotations
 
@@ -44,6 +50,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import obs
 
 _BLOCK_B = 256  # candidate rows per grid step
 _SLOT_PAD = 8  # slot (action-size) axis padded to a multiple of this
@@ -110,6 +118,7 @@ def _score_rows(dev, g, f, n, bias, mask, lam, gfree, m, lamf, mode: str):
             jax.ShapeDtypeStruct((b_pad, 1), jnp.float32),
         ],
         interpret=(mode == "interpret"),
+        name="eq1_row_scores",
     )(dev, g, f, n, bias, mask, lam, gfree, m, lamf)
 
 
@@ -148,6 +157,13 @@ def _pad_rows(a: np.ndarray, b_pad: int) -> np.ndarray:
     return out
 
 
+def _count_launch(kind: str, arrays: Sequence[np.ndarray]) -> None:
+    """One launch of entry point ``kind`` that hands the device ``arrays``."""
+    obs.count(f"kernel.launches.{kind}")
+    obs.count("kernel.h2d_arrays", len(arrays))
+    obs.count("kernel.h2d_bytes", sum(a.nbytes for a in arrays))
+
+
 def score_reduce(
     dev: np.ndarray,
     g: np.ndarray,
@@ -172,34 +188,37 @@ def score_reduce(
     candidates (default: all).  Returns (float32 scores (B,), winning row
     index) — the index is -1 when no candidate is feasible.
     """
-    B, S = dev.shape
-    b_pad = max(_BLOCK_B, 1 << max(B - 1, 0).bit_length())
-    s_pad = max(_SLOT_PAD, -(-S // _SLOT_PAD) * _SLOT_PAD)
-    dev_p = np.zeros((b_pad, s_pad), dtype=np.float32)
-    g_p = np.zeros((b_pad, s_pad), dtype=np.float32)
-    f_p = np.zeros((b_pad, s_pad), dtype=np.float32)
-    dev_p[:B, :S] = dev
-    g_p[:B, :S] = g
-    if f is not None:
-        f_p[:B, :S] = f
-    n_p = _pad_rows(np.asarray(n, dtype=np.float32).reshape(B, 1), b_pad)
-    bias_p = (
-        _pad_rows(np.asarray(bias, dtype=np.float32).reshape(B, 1), b_pad)
-        if bias is not None
-        else np.zeros((b_pad, 1), dtype=np.float32)
-    )
-    feasible = (
-        np.asarray(mask, dtype=np.float32).reshape(B, 1)
-        if mask is not None
-        else np.ones((B, 1), dtype=np.float32)
-    )
-    mask_p = _pad_rows(feasible, b_pad)  # padding rows stay masked out
-    params = np.array([[lam, g_free, M, lam_f]], dtype=np.float32)
-    scores, best = _reduce_jit(
-        params, dev_p, g_p, f_p, n_p, bias_p, mask_p,
-        mode=backend_mode(mode),
-    )
-    return np.asarray(scores)[:B], int(best)
+    with obs.span("kernel.pack"):
+        B, S = dev.shape
+        b_pad = max(_BLOCK_B, 1 << max(B - 1, 0).bit_length())
+        s_pad = max(_SLOT_PAD, -(-S // _SLOT_PAD) * _SLOT_PAD)
+        dev_p = np.zeros((b_pad, s_pad), dtype=np.float32)
+        g_p = np.zeros((b_pad, s_pad), dtype=np.float32)
+        f_p = np.zeros((b_pad, s_pad), dtype=np.float32)
+        dev_p[:B, :S] = dev
+        g_p[:B, :S] = g
+        if f is not None:
+            f_p[:B, :S] = f
+        n_p = _pad_rows(np.asarray(n, dtype=np.float32).reshape(B, 1), b_pad)
+        bias_p = (
+            _pad_rows(np.asarray(bias, dtype=np.float32).reshape(B, 1), b_pad)
+            if bias is not None
+            else np.zeros((b_pad, 1), dtype=np.float32)
+        )
+        feasible = (
+            np.asarray(mask, dtype=np.float32).reshape(B, 1)
+            if mask is not None
+            else np.ones((B, 1), dtype=np.float32)
+        )
+        mask_p = _pad_rows(feasible, b_pad)  # padding rows stay masked out
+        params = np.array([[lam, g_free, M, lam_f]], dtype=np.float32)
+        args = (params, dev_p, g_p, f_p, n_p, bias_p, mask_p)
+        _count_launch("solo", args)
+        mode = backend_mode(mode)
+    with obs.span("kernel.call"):
+        scores, best = _reduce_jit(*args, mode=mode)
+    with obs.span("kernel.fetch"):
+        return np.asarray(scores)[:B], int(best)
 
 
 # ---------------------------------------------------------------------------
@@ -242,45 +261,48 @@ def score_reduce_batch(
     """
     if not reqs:
         return []
-    sizes = [r["dev"].shape for r in reqs]
-    b_max = max(b for b, _ in sizes)
-    s_max = max(s for _, s in sizes)
-    b_pad = max(_BLOCK_B, 1 << max(b_max - 1, 0).bit_length())
-    s_pad = max(_SLOT_PAD, -(-s_max // _SLOT_PAD) * _SLOT_PAD)
-    D = len(reqs)
-    d_pad = 1 << max(D - 1, 0).bit_length()
-    dev = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
-    g = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
-    f = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
-    n = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
-    bias = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
-    mask = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
-    params = np.zeros((d_pad, 4), dtype=np.float32)
-    params[:, 2] = 1.0  # benign M for the masked pad nodes (no 0/0)
-    for k, r in enumerate(reqs):
-        B, S = sizes[k]
-        dev[k, :B, :S] = r["dev"]
-        g[k, :B, :S] = r["g"]
-        rf = r.get("f")
-        if rf is not None:
-            f[k, :B, :S] = rf
-        n[k, :B, 0] = np.asarray(r["n"], dtype=np.float32).reshape(B)
-        rb = r.get("bias")
-        if rb is not None:
-            bias[k, :B, 0] = np.asarray(rb, dtype=np.float32).reshape(B)
-        rm = r.get("mask")
-        if rm is None:
-            mask[k, :B, 0] = 1.0
-        else:
-            mask[k, :B, 0] = np.asarray(rm, dtype=np.float32).reshape(B)
-        params[k] = [r["lam"], r["g_free"], r["M"], r.get("lam_f", 0.0)]
-    scores, best = _reduce_batch_jit(
-        params, dev, g, f, n, bias, mask,
-        mode=backend_mode(mode),
-    )
-    scores = np.asarray(scores)
-    best = np.asarray(best)
-    return [(scores[k, : sizes[k][0]], int(best[k])) for k in range(D)]
+    with obs.span("kernel.pack"):
+        sizes = [r["dev"].shape for r in reqs]
+        b_max = max(b for b, _ in sizes)
+        s_max = max(s for _, s in sizes)
+        b_pad = max(_BLOCK_B, 1 << max(b_max - 1, 0).bit_length())
+        s_pad = max(_SLOT_PAD, -(-s_max // _SLOT_PAD) * _SLOT_PAD)
+        D = len(reqs)
+        d_pad = 1 << max(D - 1, 0).bit_length()
+        dev = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
+        g = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
+        f = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
+        n = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
+        bias = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
+        mask = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
+        params = np.zeros((d_pad, 4), dtype=np.float32)
+        params[:, 2] = 1.0  # benign M for the masked pad nodes (no 0/0)
+        for k, r in enumerate(reqs):
+            B, S = sizes[k]
+            dev[k, :B, :S] = r["dev"]
+            g[k, :B, :S] = r["g"]
+            rf = r.get("f")
+            if rf is not None:
+                f[k, :B, :S] = rf
+            n[k, :B, 0] = np.asarray(r["n"], dtype=np.float32).reshape(B)
+            rb = r.get("bias")
+            if rb is not None:
+                bias[k, :B, 0] = np.asarray(rb, dtype=np.float32).reshape(B)
+            rm = r.get("mask")
+            if rm is None:
+                mask[k, :B, 0] = 1.0
+            else:
+                mask[k, :B, 0] = np.asarray(rm, dtype=np.float32).reshape(B)
+            params[k] = [r["lam"], r["g_free"], r["M"], r.get("lam_f", 0.0)]
+        args = (params, dev, g, f, n, bias, mask)
+        _count_launch("batch", args)
+        mode = backend_mode(mode)
+    with obs.span("kernel.call"):
+        scores, best = _reduce_batch_jit(*args, mode=mode)
+    with obs.span("kernel.fetch"):
+        scores = np.asarray(scores)
+        best = np.asarray(best)
+        return [(scores[k, : sizes[k][0]], int(best[k])) for k in range(D)]
 
 
 # ---------------------------------------------------------------------------
@@ -341,61 +363,65 @@ def score_reduce_multi(
     """
     if not reqs:
         return []
-    sizes = [r["dev"].shape for r in reqs]
-    total = sum(b for b, _ in sizes)
-    s_max = max(s for _, s in sizes)
-    b_pad = max(_BLOCK_B, 1 << max(total - 1, 0).bit_length())
-    s_pad = max(_SLOT_PAD, -(-s_max // _SLOT_PAD) * _SLOT_PAD)
-    W = len(reqs)
-    # power-of-two window count strictly greater than W: the jit cache
-    # stays small and the last segment is always the pad rows' dummy
-    n_windows = 1 << max(W, 1).bit_length()
-    dev = np.zeros((b_pad, s_pad), dtype=np.float32)
-    g = np.zeros((b_pad, s_pad), dtype=np.float32)
-    f = np.zeros((b_pad, s_pad), dtype=np.float32)
-    n = np.zeros((b_pad, 1), dtype=np.float32)
-    bias = np.zeros((b_pad, 1), dtype=np.float32)
-    mask = np.zeros((b_pad, 1), dtype=np.float32)
-    lam = np.zeros((b_pad, 1), dtype=np.float32)
-    gfree = np.zeros((b_pad, 1), dtype=np.float32)
-    m = np.ones((b_pad, 1), dtype=np.float32)  # benign M for pad rows
-    lamf = np.zeros((b_pad, 1), dtype=np.float32)
-    wid = np.full(b_pad, n_windows - 1, dtype=np.int32)
-    starts = np.zeros(n_windows, dtype=np.int32)
-    off = 0
-    for k, r in enumerate(reqs):
-        B, S = sizes[k]
-        starts[k] = off
-        if B == 0:
-            continue  # empty window: stays all-inf, best = -1
-        rows = slice(off, off + B)
-        dev[rows, :S] = r["dev"]
-        g[rows, :S] = r["g"]
-        rf = r.get("f")
-        if rf is not None:
-            f[rows, :S] = rf
-        n[rows, 0] = np.asarray(r["n"], dtype=np.float32).reshape(B)
-        rb = r.get("bias")
-        if rb is not None:
-            bias[rows, 0] = np.asarray(rb, dtype=np.float32).reshape(B)
-        rm = r.get("mask")
-        if rm is None:
-            mask[rows, 0] = 1.0
-        else:
-            mask[rows, 0] = np.asarray(rm, dtype=np.float32).reshape(B)
-        lam[rows, 0] = r["lam"]
-        gfree[rows, 0] = r["g_free"]
-        m[rows, 0] = r["M"]
-        lamf[rows, 0] = r.get("lam_f", 0.0)
-        wid[rows] = k
-        off += B
-    scores, best = _reduce_multi_jit(
-        lam, gfree, m, lamf, dev, g, f, n, bias, mask, wid, starts,
-        n_windows=n_windows, mode=backend_mode(mode),
-    )
-    scores = np.asarray(scores)
-    best = np.asarray(best)
-    return [
-        (scores[int(starts[k]): int(starts[k]) + sizes[k][0]], int(best[k]))
-        for k in range(W)
-    ]
+    with obs.span("kernel.pack"):
+        sizes = [r["dev"].shape for r in reqs]
+        total = sum(b for b, _ in sizes)
+        s_max = max(s for _, s in sizes)
+        b_pad = max(_BLOCK_B, 1 << max(total - 1, 0).bit_length())
+        s_pad = max(_SLOT_PAD, -(-s_max // _SLOT_PAD) * _SLOT_PAD)
+        W = len(reqs)
+        # power-of-two window count strictly greater than W: the jit cache
+        # stays small and the last segment is always the pad rows' dummy
+        n_windows = 1 << max(W, 1).bit_length()
+        dev = np.zeros((b_pad, s_pad), dtype=np.float32)
+        g = np.zeros((b_pad, s_pad), dtype=np.float32)
+        f = np.zeros((b_pad, s_pad), dtype=np.float32)
+        n = np.zeros((b_pad, 1), dtype=np.float32)
+        bias = np.zeros((b_pad, 1), dtype=np.float32)
+        mask = np.zeros((b_pad, 1), dtype=np.float32)
+        lam = np.zeros((b_pad, 1), dtype=np.float32)
+        gfree = np.zeros((b_pad, 1), dtype=np.float32)
+        m = np.ones((b_pad, 1), dtype=np.float32)  # benign M for pad rows
+        lamf = np.zeros((b_pad, 1), dtype=np.float32)
+        wid = np.full(b_pad, n_windows - 1, dtype=np.int32)
+        starts = np.zeros(n_windows, dtype=np.int32)
+        off = 0
+        for k, r in enumerate(reqs):
+            B, S = sizes[k]
+            starts[k] = off
+            if B == 0:
+                continue  # empty window: stays all-inf, best = -1
+            rows = slice(off, off + B)
+            dev[rows, :S] = r["dev"]
+            g[rows, :S] = r["g"]
+            rf = r.get("f")
+            if rf is not None:
+                f[rows, :S] = rf
+            n[rows, 0] = np.asarray(r["n"], dtype=np.float32).reshape(B)
+            rb = r.get("bias")
+            if rb is not None:
+                bias[rows, 0] = np.asarray(rb, dtype=np.float32).reshape(B)
+            rm = r.get("mask")
+            if rm is None:
+                mask[rows, 0] = 1.0
+            else:
+                mask[rows, 0] = np.asarray(rm, dtype=np.float32).reshape(B)
+            lam[rows, 0] = r["lam"]
+            gfree[rows, 0] = r["g_free"]
+            m[rows, 0] = r["M"]
+            lamf[rows, 0] = r.get("lam_f", 0.0)
+            wid[rows] = k
+            off += B
+        args = (lam, gfree, m, lamf, dev, g, f, n, bias, mask, wid, starts)
+        _count_launch("multi", args)
+        mode = backend_mode(mode)
+    with obs.span("kernel.call"):
+        scores, best = _reduce_multi_jit(*args, n_windows=n_windows, mode=mode)
+    with obs.span("kernel.fetch"):
+        scores = np.asarray(scores)
+        best = np.asarray(best)
+        return [
+            (scores[int(starts[k]): int(starts[k]) + sizes[k][0]],
+             int(best[k]))
+            for k in range(W)
+        ]

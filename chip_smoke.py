@@ -154,23 +154,18 @@ def compile_kernels() -> None:
     import jax.numpy as jnp
     from repro.kernels import score_reduce as sr
 
-    def sds(*shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
 
     b, s, d, w = 256, 8, 16, 8
-    plane, col = sds(b, s), sds(b, 1)
+    width = 3 * s + 8  # dev | g | f planes, then eight per-row columns
     lowered = {
-        "score_reduce": sr._reduce_jit.lower(
-            sds(1, 4), plane, plane, plane, col, col, col, mode="pallas"
-        ),
+        "score_reduce": sr._reduce_jit.lower(sds(b, width), mode="pallas"),
         "score_reduce_batch": sr._reduce_batch_jit.lower(
-            sds(d, 4), sds(d, b, s), sds(d, b, s), sds(d, b, s),
-            sds(d, b, 1), sds(d, b, 1), sds(d, b, 1), mode="pallas",
+            sds(d, b, width), mode="pallas"
         ),
         "score_reduce_multi": sr._reduce_multi_jit.lower(
-            col, col, col, col, plane, plane, plane, col, col, col,
-            sds(b, dtype=jnp.int32), sds(w, dtype=jnp.int32),
-            n_windows=w, mode="pallas",
+            sds(b, width), n_windows=w, mode="pallas"
         ),
     }
     for name, low in lowered.items():

@@ -33,11 +33,23 @@ so the jit cache stays small.  Scores are float32 — parity vs the
 float64 numpy engine is ≤1e-6 over seeded random windows
 (tests/test_score_reduce.py).
 
+Each launch crosses the host/device boundary once each way.  The host
+packs every operand into one float32 table of shape
+(rows, 3·s_pad + 8): the ``dev | g | f`` planes, then eight per-row
+columns ``n, bias, mask, λ, G_free, M, λ_f, wid`` (the window id, read by
+``score_reduce_multi`` only; an exact float32 integer, never a bitcast).
+Per-launch and per-node scalars are repeated over their rows on the host.
+The jit slices the table apart on the device and returns one float32
+array: the flat scores, then each window's winning row (row indices and
+-1 are exact in float32).  The host reads it once and slices it.
+
 Each entry point runs in three spans of the program's tracer
 (``repro.obs``): ``kernel.pack`` (padding and packing on the host),
-``kernel.call`` (the jitted call) and ``kernel.fetch`` (the blocking reads
-of the answer), and counts its launch and the arrays it hands the device.
-The Pallas kernel is named ``eq1_row_scores`` in the device trace.
+``kernel.call`` (the jitted call: the table's transfer and dispatch) and
+``kernel.fetch`` (the blocking read of the answer, and slicing it), and
+counts its launch, the arrays it hands the device and the arrays it
+reads back (one each).  The Pallas kernel is named ``eq1_row_scores`` in
+the device trace.
 """
 from __future__ import annotations
 
@@ -56,6 +68,9 @@ from repro import obs
 _BLOCK_B = 256  # candidate rows per grid step
 _SLOT_PAD = 8  # slot (action-size) axis padded to a multiple of this
 _MODES = ("pallas", "interpret", "ref")
+# per-row columns after the planes: n, bias, mask, λ, G_free, M, λ_f, wid
+_N_COLS = 8
+_EXACT = 1 << 24  # float32 holds every integer below this exactly
 
 
 def backend_mode(mode: Optional[str] = None) -> str:
@@ -122,10 +137,26 @@ def _score_rows(dev, g, f, n, bias, mask, lam, gfree, m, lamf, mode: str):
     )(dev, g, f, n, bias, mask, lam, gfree, m, lamf)
 
 
-def _param_cols(params, rows: int):
-    """(D, 4) [λ, G_free, M, λ_f] rows -> four (D·rows, 1) columns, each
-    node's scalars repeated over its ``rows`` candidate rows."""
-    return [jnp.repeat(params[:, k], rows)[:, None] for k in range(4)]
+def _table_scores(table, mode: str):
+    """(R, 1) scores and total counts of a packed (R, 3·s_pad + 8) table,
+    and its (R, 1) window-id column, sliced apart on the device."""
+    s_pad = (table.shape[1] - _N_COLS) // 3
+    dev, g, f = (table[:, k * s_pad:(k + 1) * s_pad] for k in range(3))
+    c = 3 * s_pad
+    n, bias, mask, lam, gfree, m, lamf, wid = (
+        table[:, c + k:c + k + 1] for k in range(_N_COLS)
+    )
+    scores, tot = _score_rows(
+        dev, g, f, n, bias, mask, lam, gfree, m, lamf, mode=mode
+    )
+    return scores, tot, wid
+
+
+def _answer(scores, best):
+    """One float32 array for the host: flat scores, then the winning rows."""
+    return jnp.concatenate(
+        [scores.reshape(-1), best.reshape(-1).astype(jnp.float32)]
+    )
 
 
 def _argmin(scores, tot):
@@ -143,25 +174,58 @@ def _argmin(scores, tot):
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
-def _reduce_jit(params, dev, g, f, n, bias, mask, *, mode: str):
-    scores, tot = _score_rows(
-        dev, g, f, n, bias, mask, *_param_cols(params, dev.shape[0]),
-        mode=mode,
-    )
-    return _argmin(scores, tot)
+def _reduce_jit(table, *, mode: str):
+    scores, tot, _ = _table_scores(table, mode)
+    return _answer(*_argmin(scores, tot))
 
 
-def _pad_rows(a: np.ndarray, b_pad: int) -> np.ndarray:
-    out = np.zeros((b_pad,) + a.shape[1:], dtype=a.dtype)
-    out[: len(a)] = a
-    return out
+def _pads(b: int, s: int) -> Tuple[int, int]:
+    """(b_pad, s_pad): rows to a power of two of at least one block,
+    slots to a multiple of _SLOT_PAD."""
+    b_pad = max(_BLOCK_B, 1 << max(b - 1, 0).bit_length())
+    return b_pad, max(_SLOT_PAD, -(-s // _SLOT_PAD) * _SLOT_PAD)
 
 
-def _count_launch(kind: str, arrays: Sequence[np.ndarray]) -> None:
-    """One launch of entry point ``kind`` that hands the device ``arrays``."""
+def _pack(reqs: Sequence[Dict[str, Any]], offsets: Sequence[int],
+          rows: int, s_pad: int, dummy: int) -> np.ndarray:
+    """One float32 (rows, 3·s_pad + 8) table holding request ``k`` on rows
+    ``offsets[k]:offsets[k] + B_k``, with window id ``k``.  Pad rows are
+    masked out, with a benign M of 1 (no 0/0) and window id ``dummy``."""
+    if rows >= _EXACT or dummy >= _EXACT:
+        raise ValueError(f"{rows} rows or {dummy} windows: not exact in float32")
+    c = 3 * s_pad
+    table = np.zeros((rows, c + _N_COLS), dtype=np.float32)
+    table[:, c + 5] = 1.0
+    table[:, c + 7] = dummy
+    for k, (r, off) in enumerate(zip(reqs, offsets)):
+        B, S = r["dev"].shape
+        if B == 0:
+            continue  # empty window: no rows, so best = -1
+        t = table[off:off + B]
+        t[:, :S] = r["dev"]
+        t[:, s_pad:s_pad + S] = r["g"]
+        if r.get("f") is not None:
+            t[:, 2 * s_pad:2 * s_pad + S] = r["f"]
+        t[:, c] = np.asarray(r["n"]).reshape(B)
+        if r.get("bias") is not None:
+            t[:, c + 1] = np.asarray(r["bias"]).reshape(B)
+        mask = r.get("mask")
+        t[:, c + 2] = 1.0 if mask is None else np.asarray(mask).reshape(B)
+        t[:, c + 3:] = (r["lam"], r["g_free"], r["M"], r.get("lam_f", 0.0), k)
+    return table
+
+
+def _count_launch(kind: str, table: np.ndarray) -> None:
+    """One launch of entry point ``kind`` that hands the device ``table``."""
     obs.count(f"kernel.launches.{kind}")
-    obs.count("kernel.h2d_arrays", len(arrays))
-    obs.count("kernel.h2d_bytes", sum(a.nbytes for a in arrays))
+    obs.count("kernel.h2d_arrays")
+    obs.count("kernel.h2d_bytes", table.nbytes)
+
+
+def _fetch(answer) -> np.ndarray:
+    """The launch's one blocking read of its answer."""
+    obs.count("kernel.d2h_arrays")
+    return np.asarray(answer)
 
 
 def score_reduce(
@@ -190,35 +254,17 @@ def score_reduce(
     """
     with obs.span("kernel.pack"):
         B, S = dev.shape
-        b_pad = max(_BLOCK_B, 1 << max(B - 1, 0).bit_length())
-        s_pad = max(_SLOT_PAD, -(-S // _SLOT_PAD) * _SLOT_PAD)
-        dev_p = np.zeros((b_pad, s_pad), dtype=np.float32)
-        g_p = np.zeros((b_pad, s_pad), dtype=np.float32)
-        f_p = np.zeros((b_pad, s_pad), dtype=np.float32)
-        dev_p[:B, :S] = dev
-        g_p[:B, :S] = g
-        if f is not None:
-            f_p[:B, :S] = f
-        n_p = _pad_rows(np.asarray(n, dtype=np.float32).reshape(B, 1), b_pad)
-        bias_p = (
-            _pad_rows(np.asarray(bias, dtype=np.float32).reshape(B, 1), b_pad)
-            if bias is not None
-            else np.zeros((b_pad, 1), dtype=np.float32)
-        )
-        feasible = (
-            np.asarray(mask, dtype=np.float32).reshape(B, 1)
-            if mask is not None
-            else np.ones((B, 1), dtype=np.float32)
-        )
-        mask_p = _pad_rows(feasible, b_pad)  # padding rows stay masked out
-        params = np.array([[lam, g_free, M, lam_f]], dtype=np.float32)
-        args = (params, dev_p, g_p, f_p, n_p, bias_p, mask_p)
-        _count_launch("solo", args)
+        b_pad, s_pad = _pads(B, S)
+        req = dict(dev=dev, g=g, n=n, f=f, bias=bias, mask=mask,
+                   lam=lam, g_free=g_free, M=M, lam_f=lam_f)
+        table = _pack([req], [0], b_pad, s_pad, dummy=1)
+        _count_launch("solo", table)
         mode = backend_mode(mode)
     with obs.span("kernel.call"):
-        scores, best = _reduce_jit(*args, mode=mode)
+        answer = _reduce_jit(table, mode=mode)
     with obs.span("kernel.fetch"):
-        return np.asarray(scores)[:B], int(best)
+        answer = _fetch(answer)
+        return answer[:B], int(answer[b_pad])
 
 
 # ---------------------------------------------------------------------------
@@ -228,17 +274,12 @@ def score_reduce(
 
 
 @functools.partial(jax.jit, static_argnames=("mode",))
-def _reduce_batch_jit(params, dev, g, f, n, bias, mask, *, mode: str):
-    d_pad, b_pad, s_pad = dev.shape
-    rows = d_pad * b_pad
-    scores, tot = _score_rows(
-        dev.reshape(rows, s_pad), g.reshape(rows, s_pad),
-        f.reshape(rows, s_pad), n.reshape(rows, 1), bias.reshape(rows, 1),
-        mask.reshape(rows, 1), *_param_cols(params, b_pad), mode=mode,
-    )
-    return jax.vmap(_argmin)(
+def _reduce_batch_jit(table, *, mode: str):
+    d_pad, b_pad, width = table.shape
+    scores, tot, _ = _table_scores(table.reshape(d_pad * b_pad, width), mode)
+    return _answer(*jax.vmap(_argmin)(
         scores.reshape(d_pad, b_pad, 1), tot.reshape(d_pad, b_pad, 1)
-    )
+    ))
 
 
 def score_reduce_batch(
@@ -263,46 +304,21 @@ def score_reduce_batch(
         return []
     with obs.span("kernel.pack"):
         sizes = [r["dev"].shape for r in reqs]
-        b_max = max(b for b, _ in sizes)
-        s_max = max(s for _, s in sizes)
-        b_pad = max(_BLOCK_B, 1 << max(b_max - 1, 0).bit_length())
-        s_pad = max(_SLOT_PAD, -(-s_max // _SLOT_PAD) * _SLOT_PAD)
+        b_pad, s_pad = _pads(max(b for b, _ in sizes), max(s for _, s in sizes))
         D = len(reqs)
         d_pad = 1 << max(D - 1, 0).bit_length()
-        dev = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
-        g = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
-        f = np.zeros((d_pad, b_pad, s_pad), dtype=np.float32)
-        n = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
-        bias = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
-        mask = np.zeros((d_pad, b_pad, 1), dtype=np.float32)
-        params = np.zeros((d_pad, 4), dtype=np.float32)
-        params[:, 2] = 1.0  # benign M for the masked pad nodes (no 0/0)
-        for k, r in enumerate(reqs):
-            B, S = sizes[k]
-            dev[k, :B, :S] = r["dev"]
-            g[k, :B, :S] = r["g"]
-            rf = r.get("f")
-            if rf is not None:
-                f[k, :B, :S] = rf
-            n[k, :B, 0] = np.asarray(r["n"], dtype=np.float32).reshape(B)
-            rb = r.get("bias")
-            if rb is not None:
-                bias[k, :B, 0] = np.asarray(rb, dtype=np.float32).reshape(B)
-            rm = r.get("mask")
-            if rm is None:
-                mask[k, :B, 0] = 1.0
-            else:
-                mask[k, :B, 0] = np.asarray(rm, dtype=np.float32).reshape(B)
-            params[k] = [r["lam"], r["g_free"], r["M"], r.get("lam_f", 0.0)]
-        args = (params, dev, g, f, n, bias, mask)
-        _count_launch("batch", args)
+        # node k on rows k·b_pad onwards; pad nodes are all pad rows
+        table = _pack(reqs, range(0, D * b_pad, b_pad), d_pad * b_pad,
+                      s_pad, dummy=D).reshape(d_pad, b_pad, -1)
+        _count_launch("batch", table)
         mode = backend_mode(mode)
     with obs.span("kernel.call"):
-        scores, best = _reduce_batch_jit(*args, mode=mode)
+        answer = _reduce_batch_jit(table, mode=mode)
     with obs.span("kernel.fetch"):
-        scores = np.asarray(scores)
-        best = np.asarray(best)
-        return [(scores[k, : sizes[k][0]], int(best[k])) for k in range(D)]
+        answer = _fetch(answer)
+        best = answer[d_pad * b_pad:]
+        return [(answer[k * b_pad:k * b_pad + sizes[k][0]], int(best[k]))
+                for k in range(D)]
 
 
 # ---------------------------------------------------------------------------
@@ -317,14 +333,12 @@ def score_reduce_batch(
 
 
 @functools.partial(jax.jit, static_argnames=("n_windows", "mode"))
-def _reduce_multi_jit(lam, gfree, m, lamf, dev, g, f, n, bias, mask,
-                      wid, starts, *, n_windows: int, mode: str):
-    b_pad = dev.shape[0]
-    scores2, tot2 = _score_rows(
-        dev, g, f, n, bias, mask, lam, gfree, m, lamf, mode=mode
-    )
+def _reduce_multi_jit(table, *, n_windows: int, mode: str):
+    b_pad = table.shape[0]
+    scores2, tot2, wid2 = _table_scores(table, mode)
     scores = scores2[:, 0]
     tot = tot2[:, 0]
+    wid = wid2[:, 0].astype(jnp.int32)
     # segmented tie-broken argmin — the same (min score, max count, min
     # row) combine as _argmin, scatter-reduced per window id.  Pad rows
     # belong to a dummy window (their masked inf scores never matter).
@@ -337,8 +351,10 @@ def _reduce_multi_jit(lam, gfree, m, lamf, dev, g, f, n, bias, mask,
     ridx = jax.lax.iota(jnp.int32, b_pad)
     seg_idx = jnp.full((n_windows,), b_pad, dtype=jnp.int32)
     i_w = seg_idx.at[wid].min(jnp.where(cand, ridx, jnp.int32(b_pad)))
-    best = jnp.where(jnp.isinf(m_w), jnp.int32(-1), i_w - starts)
-    return scores, best
+    # a window's first row is its least row index; an empty or
+    # all-infeasible window keeps m_w = inf and answers -1
+    first = seg_idx.at[wid].min(ridx)
+    return _answer(scores, jnp.where(jnp.isinf(m_w), jnp.int32(-1), i_w - first))
 
 
 def score_reduce_multi(
@@ -365,63 +381,19 @@ def score_reduce_multi(
         return []
     with obs.span("kernel.pack"):
         sizes = [r["dev"].shape for r in reqs]
-        total = sum(b for b, _ in sizes)
-        s_max = max(s for _, s in sizes)
-        b_pad = max(_BLOCK_B, 1 << max(total - 1, 0).bit_length())
-        s_pad = max(_SLOT_PAD, -(-s_max // _SLOT_PAD) * _SLOT_PAD)
+        b_pad, s_pad = _pads(sum(b for b, _ in sizes), max(s for _, s in sizes))
         W = len(reqs)
         # power-of-two window count strictly greater than W: the jit cache
         # stays small and the last segment is always the pad rows' dummy
         n_windows = 1 << max(W, 1).bit_length()
-        dev = np.zeros((b_pad, s_pad), dtype=np.float32)
-        g = np.zeros((b_pad, s_pad), dtype=np.float32)
-        f = np.zeros((b_pad, s_pad), dtype=np.float32)
-        n = np.zeros((b_pad, 1), dtype=np.float32)
-        bias = np.zeros((b_pad, 1), dtype=np.float32)
-        mask = np.zeros((b_pad, 1), dtype=np.float32)
-        lam = np.zeros((b_pad, 1), dtype=np.float32)
-        gfree = np.zeros((b_pad, 1), dtype=np.float32)
-        m = np.ones((b_pad, 1), dtype=np.float32)  # benign M for pad rows
-        lamf = np.zeros((b_pad, 1), dtype=np.float32)
-        wid = np.full(b_pad, n_windows - 1, dtype=np.int32)
-        starts = np.zeros(n_windows, dtype=np.int32)
-        off = 0
-        for k, r in enumerate(reqs):
-            B, S = sizes[k]
-            starts[k] = off
-            if B == 0:
-                continue  # empty window: stays all-inf, best = -1
-            rows = slice(off, off + B)
-            dev[rows, :S] = r["dev"]
-            g[rows, :S] = r["g"]
-            rf = r.get("f")
-            if rf is not None:
-                f[rows, :S] = rf
-            n[rows, 0] = np.asarray(r["n"], dtype=np.float32).reshape(B)
-            rb = r.get("bias")
-            if rb is not None:
-                bias[rows, 0] = np.asarray(rb, dtype=np.float32).reshape(B)
-            rm = r.get("mask")
-            if rm is None:
-                mask[rows, 0] = 1.0
-            else:
-                mask[rows, 0] = np.asarray(rm, dtype=np.float32).reshape(B)
-            lam[rows, 0] = r["lam"]
-            gfree[rows, 0] = r["g_free"]
-            m[rows, 0] = r["M"]
-            lamf[rows, 0] = r.get("lam_f", 0.0)
-            wid[rows] = k
-            off += B
-        args = (lam, gfree, m, lamf, dev, g, f, n, bias, mask, wid, starts)
-        _count_launch("multi", args)
+        starts = np.cumsum([0] + [b for b, _ in sizes[:-1]]).tolist()
+        table = _pack(reqs, starts, b_pad, s_pad, dummy=n_windows - 1)
+        _count_launch("multi", table)
         mode = backend_mode(mode)
     with obs.span("kernel.call"):
-        scores, best = _reduce_multi_jit(*args, n_windows=n_windows, mode=mode)
+        answer = _reduce_multi_jit(table, n_windows=n_windows, mode=mode)
     with obs.span("kernel.fetch"):
-        scores = np.asarray(scores)
-        best = np.asarray(best)
-        return [
-            (scores[int(starts[k]): int(starts[k]) + sizes[k][0]],
-             int(best[k]))
-            for k in range(W)
-        ]
+        answer = _fetch(answer)
+        best = answer[b_pad:]
+        return [(answer[starts[k]:starts[k] + sizes[k][0]], int(best[k]))
+                for k in range(W)]

@@ -26,10 +26,12 @@ Spans and counters, by layer:
     kernel     kernel.pack     each ``score_reduce*`` entry point: padding
                                and packing the operands on the host
                kernel.call     the jitted call: argument transfer, dispatch
-               kernel.fetch    the blocking reads of the answer, slicing
+               kernel.fetch    the blocking read of the answer, slicing
                counters ``kernel.launches.{solo,batch,multi}``,
                ``kernel.h2d_arrays`` and ``kernel.h2d_bytes`` (the host
-               arrays each call hands the device)
+               arrays each call hands the device: one packed table),
+               ``kernel.d2h_arrays`` (the device arrays ``kernel.fetch``
+               reads back: one answer)
 """
 from __future__ import annotations
 

@@ -1,14 +1,23 @@
 """JAX/Pallas score-reduce kernel (kernels/score_reduce.py): parity of the
 pallas-interpret and pure-jnp ref paths against the numpy engine over seeded
-random windows, edge cases (empty window, all-infeasible candidates), and the
-EcoSched engine="jax" end-to-end wiring."""
+random windows, edge cases (empty window, all-infeasible candidates), the
+packed host/device boundary against unpacked operands bit for bit, the jit
+names the benchmark's trace reducer reads, and the EcoSched engine="jax"
+end-to-end wiring."""
+import functools
+import itertools
+import re
+
+import jax
 import numpy as np
 import pytest
 
+from bench.trace import REDUCTION_MODULE
 from repro.core import EcoSched, JobProfile, Node, ProfiledPerfModel, simulate
 from repro.core.engine import enumerate_scored
 from repro.core.perfmodel import _mk_spec
 from repro.core.types import NodeView
+from repro.kernels import score_reduce as sr
 from repro.kernels.score_reduce import (
     score_reduce,
     score_reduce_batch,
@@ -281,6 +290,142 @@ def test_batch_per_node_params_ride_in_smem():
         s_solo, b_solo = score_reduce(dev, g, n, f=f, mode="ref", **c)
         assert best == b_solo
         assert np.array_equal(scores, s_solo)
+
+
+# ---------------------------------------------------------------------------
+# The packed boundary: one table in, one answer out, bit for bit the
+# reduction of the same request from separate float32 operands
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("mode",))
+def _unpacked_jit(dev, g, f, n, bias, mask, lam, gfree, m, lamf, *, mode):
+    return sr._argmin(*sr._score_rows(
+        dev, g, f, n, bias, mask, lam, gfree, m, lamf, mode=mode))
+
+
+def unpacked(r, s_pad, mode):
+    """(float32 scores, best) of request ``r`` from ten separate float32
+    operands padded to ``s_pad`` slots: no table, no packed answer."""
+    B, S = r["dev"].shape
+    b_pad = max(256, 1 << max(B - 1, 0).bit_length())
+
+    def plane(a):
+        out = np.zeros((b_pad, s_pad), dtype=np.float32)
+        if a is not None:
+            out[:B, :S] = a
+        return out
+
+    def col(a, pad=0.0):
+        out = np.full((b_pad, 1), pad, dtype=np.float32)
+        if a is not None:
+            out[:B, 0] = np.broadcast_to(np.asarray(a, dtype=np.float32), (B,))
+        return out
+
+    mask = r.get("mask")
+    scores, best = _unpacked_jit(
+        plane(r["dev"]), plane(r["g"]), plane(r.get("f")), col(r["n"]),
+        col(r.get("bias")), col(np.ones(B) if mask is None else mask),
+        col(r["lam"]), col(r["g_free"]), col(r["M"], pad=1.0),
+        col(r.get("lam_f", 0.0)), mode=mode,
+    )
+    return np.asarray(scores)[:B], int(best)
+
+
+def request(seed, B, S=5, *, f=False, bias=False, mask=False, **params):
+    rng = np.random.default_rng(seed)
+    r = dict(dev=rng.random((B, S)), g=rng.integers(0, 4, (B, S)).astype(float),
+             n=rng.integers(0, S + 1, B), lam=0.35, g_free=8, M=8)
+    if f:
+        r["f"] = rng.integers(0, 4, (B, S)).astype(float)
+        r["lam_f"] = 0.25
+    if bias:
+        r["bias"] = rng.uniform(0.0, 0.5, B)
+    if mask:
+        r["mask"] = rng.random(B) < 0.7
+    r.update(params)
+    return r
+
+
+def last_row_wins(B):
+    """B rows whose last row alone scores lowest."""
+    r = request(40, B)
+    r["dev"] = np.ones_like(r["dev"])
+    r["dev"][-1] = 0.0
+    return r
+
+
+BOUNDARY_CASES = {
+    **{f"f{int(f)}-bias{int(b)}-mask{int(m)}":
+       [request(k, 7 + 3 * k, f=f, bias=b, mask=m) for k in range(3)]
+       for f, b, m in itertools.product([False, True], repeat=3)},
+    "empty-windows": [request(1, 0), request(2, 9, f=True), request(3, 0),
+                      request(4, 4, bias=True)],
+    "all-infeasible": [request(5, 6, mask=True) | dict(mask=np.zeros(6, bool)),
+                       request(6, 11),
+                       request(7, 3) | dict(mask=np.zeros(3, bool))],
+    # the winner on the table's last row, b_pad - 1: at 255 of 256 rows for
+    # every entry point, then at the last packed row of a multi launch
+    "winner-last-row": [last_row_wins(256)],
+    "winner-last-packed-row": [request(8, 56), last_row_wins(200)],
+    "heterogeneous-params": [
+        request(9 + k, 12, S=3 + k, f=True, lam=lam, g_free=gf, M=M, lam_f=lf)
+        for k, (lam, gf, M, lf) in enumerate(
+            [(0.1, 2, 4, 0.0), (0.9, 16, 16, 0.25), (0.35, 8, 8, 0.5),
+             (0.6, 0, 12, 0.125)])],
+}
+
+
+def assert_bitwise(got, want):
+    (scores, best), (ref_scores, ref_best) = got, want
+    assert scores.dtype == np.float32 and scores.shape == ref_scores.shape
+    assert np.array_equal(scores.view(np.uint32), ref_scores.view(np.uint32))
+    assert type(best) is int and best == ref_best
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+@pytest.mark.parametrize("case", list(BOUNDARY_CASES))
+def test_packed_boundary_is_bitwise_unpacked(case, mode):
+    reqs = BOUNDARY_CASES[case]
+    for r in reqs:
+        kw = {k: v for k, v in r.items() if k not in ("dev", "g", "n")}
+        s_pad = sr._pads(*r["dev"].shape)[1]
+        assert_bitwise(score_reduce(r["dev"], r["g"], r["n"], mode=mode, **kw),
+                       unpacked(r, s_pad, mode))
+    s_pad = sr._pads(1, max(r["dev"].shape[1] for r in reqs))[1]
+    for entry in (score_reduce_batch, score_reduce_multi):
+        out = entry(reqs, mode=mode)
+        assert len(out) == len(reqs)
+        for got, r in zip(out, reqs):
+            assert_bitwise(got, unpacked(r, s_pad, mode))
+    if case in ("empty-windows", "all-infeasible"):
+        assert [b for _, b in score_reduce_multi(reqs, mode=mode)].count(-1) == 2
+    if case == "winner-last-row":
+        assert score_reduce(*(reqs[0][k] for k in ("dev", "g", "n")), lam=0.35,
+                            g_free=8, M=8, mode=mode)[1] == 255
+
+
+def test_pack_refuses_rows_float32_cannot_index():
+    with pytest.raises(ValueError, match="float32"):
+        sr._pack([], [], 1 << 24, 8, dummy=0)
+
+
+def test_jit_module_names_are_what_the_trace_reducer_reads():
+    """``bench/trace.py`` times the three reductions by their module names
+    for ``score_reduce_roofline``: a rename would silence it on the chip."""
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, np.float32)
+
+    lowered = [
+        sr._reduce_jit.lower(shape(256, 32), mode="ref"),
+        sr._reduce_batch_jit.lower(shape(2, 256, 32), mode="ref"),
+        sr._reduce_multi_jit.lower(shape(256, 32), n_windows=4, mode="ref"),
+    ]
+    names = [re.search(r"^module @([\w.]+)", low.as_text(), re.M).group(1)
+             for low in lowered]
+    assert names == ["jit__reduce_jit", "jit__reduce_batch_jit",
+                     "jit__reduce_multi_jit"]
+    assert all(REDUCTION_MODULE.match(n) for n in names)
 
 
 def test_engine_jax_end_to_end_matches_vector():

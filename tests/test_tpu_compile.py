@@ -53,9 +53,12 @@ def no_persistent_cache():
 
 
 @pytest.fixture
-def sds(one_chip, no_persistent_cache):
-    def make(*shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+def table(one_chip, no_persistent_cache):
+    """The packed operand table of ``rows`` x ``s_pad`` slots (a leading
+    node axis first, for the batch), as a shape on the described chip."""
+    def make(*rows, s_pad):
+        return jax.ShapeDtypeStruct((*rows, 3 * s_pad + 8), jnp.float32,
+                                    sharding=one_chip)
 
     return make
 
@@ -65,30 +68,23 @@ def assert_kernel(lowered):
 
 
 @pytest.mark.parametrize("b_pad,s_pad", [(256, 8), (65536, 16)])
-def test_solo_reduce_compiles_for_v5e(sds, b_pad, s_pad):
-    plane, col = sds(b_pad, s_pad), sds(b_pad, 1)
-    assert_kernel(sr._reduce_jit.lower(
-        sds(1, 4), plane, plane, plane, col, col, col, mode="pallas"
-    ))
+def test_solo_reduce_compiles_for_v5e(table, b_pad, s_pad):
+    assert_kernel(sr._reduce_jit.lower(table(b_pad, s_pad=s_pad), mode="pallas"))
 
 
 @pytest.mark.parametrize("d_pad,b_pad,s_pad", [(16, 256, 8), (64, 4096, 16)])
-def test_batch_reduce_compiles_for_v5e(sds, d_pad, b_pad, s_pad):
-    plane, col = sds(d_pad, b_pad, s_pad), sds(d_pad, b_pad, 1)
+def test_batch_reduce_compiles_for_v5e(table, d_pad, b_pad, s_pad):
     assert_kernel(sr._reduce_batch_jit.lower(
-        sds(d_pad, 4), plane, plane, plane, col, col, col, mode="pallas"
+        table(d_pad, b_pad, s_pad=s_pad), mode="pallas"
     ))
 
 
 @pytest.mark.parametrize(
     "b_pad,s_pad,n_windows", [(256, 8, 8), (65536, 16, 512)]
 )
-def test_multi_reduce_compiles_for_v5e(sds, b_pad, s_pad, n_windows):
-    plane, col = sds(b_pad, s_pad), sds(b_pad, 1)
+def test_multi_reduce_compiles_for_v5e(table, b_pad, s_pad, n_windows):
     assert_kernel(sr._reduce_multi_jit.lower(
-        col, col, col, col, plane, plane, plane, col, col, col,
-        sds(b_pad, dtype=jnp.int32), sds(n_windows, dtype=jnp.int32),
-        n_windows=n_windows, mode="pallas",
+        table(b_pad, s_pad=s_pad), n_windows=n_windows, mode="pallas",
     ))
 
 

@@ -70,3 +70,11 @@ def elastic_config(traffic: dict):
 
     e = traffic.get("elastic")
     return None if e is None else ElasticConfig(**e)
+
+
+def fault_config(traffic: dict):
+    """The traffic's ``FaultConfig``, or None when it has no fault section."""
+    from repro.core import FaultConfig
+
+    f = T.faults(traffic)
+    return None if f is None else FaultConfig(**f)

@@ -10,19 +10,36 @@ seconds have passed; the last one is cut between two instants.  Finite
 replays keep the cost of an event steady: an endless stream at these rates
 backs up without bound.
 
-An event is one arrival routed, one launch or one segment completion, as
-``ClusterRun`` reports them through its transition hook.  An instant is
-every event at one simulated time, timed from taking its first event to
+A traffic file with a fault section (``bench/traffic.py``) hands its
+``FaultConfig`` to ``open_run``; one without hands ``faults=None``.  Under
+faults the node failure and repair timeline regenerates forever, so a
+replay drains where the program's own ``EventLoop.run`` stops: when
+``loop.idle()`` holds (no work event pending, no job waiting) before the
+next event, which may leave timeline events in the heap.  The test is
+chosen once per replay; a replay without faults steps as it always did,
+until the heap is empty.
+
+An event is one arrival routed, one launch, one segment completion, one
+segment killed by a fault, or one killed job handed back to a queue by a
+retry, as ``ClusterRun`` reports them through its transition hook
+(``queued``, ``launch``, ``done`` or ``ckpt``, ``fail``, ``retry``).
+Requeues, migrations and lost jobs are not counted.  An instant is every
+event at one simulated time, timed from taking its first event to
 finishing its last.
 
 Once the window has closed, every kernel request of the window is checked
 against a float64 argmin, and the schedule records and total energy of
 every replay against the plain reference (``bench/reference.py``) on the
-same stream; a cut replay's launches must all be launches of the
-reference.  Every node decision that launched the kernel itself (not one
-served from a staged batch or a cache) has its answered best score held
-to the reference's float64 score of the same decision.  Set-up warms up
-on a stream drawn from another seed.
+same stream, faults included; a cut replay's launches must all be
+launches of the reference.  Every node decision that launched the kernel
+itself (not one served from a staged batch or a cache) has its answered
+best score held to the reference's float64 score of the same decision.
+Set-up warms up on a stream drawn from another seed.
+
+``run_cell`` reads the program's own tracer (``repro.obs``) just before
+and just after the window, outside its timed region; ``report.context``
+hands metric readers what the program's spans and counters made in
+between (``program_spans``, ``program_counts``).
 """
 from __future__ import annotations
 
@@ -42,7 +59,7 @@ from bench.spans import Spans
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
-COUNTED = frozenset(("queued", "launch", "done", "ckpt"))
+COUNTED = frozenset(("queued", "launch", "done", "ckpt", "fail", "retry"))
 WARM_SEED_SALT = 0x9E3779B97F4A7C15  # the warm-up stream's seed is never the window's
 # widest |kernel score - float64 score| over every feasible row of every
 # request in the window; set from the readings in PERF.md ("The check")
@@ -143,7 +160,8 @@ def replay(cell: dict, arrivals, deadline: float = math.inf,
 
     run = F.cluster(cell["config"], cell["profiles"]).open_run(
         apps=cell["apps"], jobs=[(n, a) for _, n, a in arrivals],
-        elastic=F.elastic_config(cell["traffic"]), on_transition=on_transition,
+        elastic=F.elastic_config(cell["traffic"]),
+        faults=F.fault_config(cell["traffic"]), on_transition=on_transition,
     )
     loop = run.loop
     if recorder is not None:
@@ -162,9 +180,15 @@ def replay(cell: dict, arrivals, deadline: float = math.inf,
         loop.queue.push(t, EVT_ARRIVAL, Arrival(t=t, name=n, app=a))
 
     clock, queue = time.perf_counter, loop.queue
+    peek = queue.peek_time
+    if loop.faults is not None:
+        idle = loop.idle
+
+        def peek():  # the program's stop: only the node timeline is left
+            return None if idle() else queue.peek_time()
 
     def instant(t):
-        while queue.peek_time() == t:
+        while peek() == t:
             loop.step()
 
     def start():
@@ -179,7 +203,7 @@ def replay(cell: dict, arrivals, deadline: float = math.inf,
         start()
         durations.append(clock() - t0)
         while True:
-            t = queue.peek_time()
+            t = peek()
             if t is None:
                 break
             if clock() >= deadline:
@@ -276,7 +300,8 @@ def check(cell: dict, win: dict, recorder: A.Recorder) -> Dict[str, dict]:
     compared = decided = 0
     for r in win["replays"]:
         ref = Reference(T.nodes(cell["config"]), cell["profiles"],
-                        cell["config"]["scheduler"], cell["traffic"]["elastic"])
+                        cell["config"]["scheduler"], cell["traffic"]["elastic"],
+                        T.faults(cell["traffic"]))
         ref_records, ref_energy, ref_launches = ref.run(r["arrivals"])
         ref_records = sorted(ref_records)
         ref_launch_set = set(ref_launches)
@@ -333,8 +358,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     ``setup_s`` counts.  ``trace`` records the benchmark's spans, and
     ``trace_dir`` also the profiler's trace.  ``replace`` swaps what runs
     under the three reduction entry points (the control, or a planted
-    fault)."""
+    fault).  The result's ``program`` holds ``obs.snapshot()`` read just
+    before and just after the window."""
     from bench.compile_events import CompileCounter
+    from repro import obs
 
     t_start = time.perf_counter() if t_start is None else t_start
     compiles = CompileCounter()
@@ -355,6 +382,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
     recorder = A.Recorder()
     spans = Spans(annotate=trace_dir is not None) if trace else None
+    before = obs.snapshot()
     with A.patched_reductions(recorder, spans, replace=replace):
         if warm_error is not None:
             win = {"replays": [], "seconds": 0.0, "error": warm_error}
@@ -374,10 +402,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                 jax.profiler.stop_trace()
         else:
             win = window(cell, seed, seconds, spans, recorder)
+    program = (before, obs.snapshot())
     window_compiles = compiles.since(snap)
     result = {"setup_s": setup_s, "window": win, "recorder": recorder,
               "spans": spans, "window_compiles": window_compiles,
-              "warm_compiles": warm_compiles, "setup_phases": phases}
+              "warm_compiles": warm_compiles, "setup_phases": phases,
+              "program": program}
     return result
 
 

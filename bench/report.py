@@ -4,17 +4,29 @@ from __future__ import annotations
 from typing import List, Optional, Tuple
 
 from bench import harness as H
+from bench import program_spans as P
 from bench import roofline as R
+
+
+def program_window(before: dict, after: dict) -> Tuple[dict, dict]:
+    """What the program's tracer made between two ``obs.snapshot()``
+    readings: spans (name -> ``self_s``, ``total_s``, ``calls``) that ended
+    in between, and counters (name -> count) that moved."""
+    spans, counts = P.since(before, after)
+    return ({n: s for n, s in spans.items() if s["calls"]},
+            {n: k for n, k in counts.items() if k})
 
 
 def context(cell: dict, res: dict, device: dict, trace: Optional[dict]) -> dict:
     """What the metric readers read: the window's counts, the benchmark's
-    spans, the profiler trace's reduction and the kernels' least work."""
+    spans, the program's spans and counters over the window, the profiler
+    trace's reduction and the kernels' least work."""
     ctx = H.summarize(cell, res)
     ctx["setup_s"] = res["setup_s"]
     spans = res["spans"]
     ctx["span_self_s"] = dict(spans.self_s) if spans is not None else {}
     ctx["span_calls"] = dict(spans.calls) if spans is not None else {}
+    ctx["program_spans"], ctx["program_counts"] = program_window(*res["program"])
     ctx["trace"] = trace
     ctx["kernel_least_s"] = None
     if trace is not None:

@@ -1,14 +1,23 @@
 """The benchmark's one traffic generator, driven by a traffic file.
 
 A traffic file (``bench/traffic/<name>.json``) names an arrival process, an
-application mix and the elastic settings:
+application mix, the elastic settings and, optionally, a fault process:
 
     {"arrivals": {"kind": "bursty" | "poisson", "rate": jobs/s,
                   "jobs": n, "burst": max burst size (bursty only),
                   "shape_seed": s},
      "apps": {"family": "anchor_grow" | "three_family", "seed": s,
               "n_apps": k}  or  {"family": "config"},
-     "elastic": null | {ElasticConfig fields}}
+     "elastic": null | {ElasticConfig fields},
+     "faults": null | {FaultConfig fields}}
+
+``faults`` is optional; absent or null, the run takes the program's
+pre-fault path (``faults=None``).  A fault section names any of
+``FaultConfig``'s fields; ``faults`` fills the rest from
+``FAULT_DEFAULTS``, so the program and the reference see the same
+complete numbers.  Its ``seed`` is fixed in the file, like
+``shape_seed``: every run and every replay faces the same node, crash and
+straggler streams, and ``--seed`` still only reorders the submissions.
 
 Every replay offers the same set of submissions: the gaps, burst sizes and
 apps are drawn once from ``shape_seed``, and the run's ``--seed`` with the
@@ -28,11 +37,20 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+# ``FaultConfig``'s documented defaults, copied so that a later change to
+# the program's defaults cannot move a cell's fault process
+FAULT_DEFAULTS = {
+    "seed": 0, "node_mtbf_s": 0.0, "node_mttr_s": 600.0,
+    "degrade_frac": 0.0, "degrade_units": 1, "job_mtbf_s": 0.0,
+    "straggler_prob": 0.0, "straggler_factor": 1.5, "max_retries": 3,
+    "retry_base_s": 30.0, "retry_mult": 2.0, "retry_cap_s": 1800.0,
+    "restart_time": 15.0,
+}
 
 Arrival = Tuple[float, str, str]  # (t, instance name, app)
 
@@ -40,6 +58,18 @@ Arrival = Tuple[float, str, str]  # (t, instance name, app)
 def load(name: str) -> dict:
     with open(os.path.join(HERE, "traffic", f"{name}.json")) as fh:
         return json.load(fh)
+
+
+def faults(traffic: dict) -> Optional[dict]:
+    """The traffic's fault section with every field stated, or None when
+    it has none."""
+    f = traffic.get("faults")
+    if f is None:
+        return None
+    unknown = set(f) - set(FAULT_DEFAULTS)
+    if unknown:
+        raise ValueError(f"unknown fault fields {sorted(unknown)}")
+    return {**FAULT_DEFAULTS, **f}
 
 
 def poisson_stream(apps: Sequence[str], *, rate: float, n: int,
